@@ -54,6 +54,8 @@ def main() -> None:
                          "the fused run_rounds engine (A/B timing)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.no_fuse:
         from benchmarks import common
         common.FUSE_ROUNDS = False

@@ -269,6 +269,30 @@ def _scatter_units(entries, unit_sizes, dtype):
     return [flat[off:off + n] for off, n in zip(offs, unit_sizes)]
 
 
+def _by_shape(fn, units, statics):
+    """``[fn(u, s) for u, s in zip(units, statics)]`` with one vmapped call
+    per distinct ``(shape, dtype, s)``.
+
+    A model's repeated layers then trace one kernel group per leaf shape
+    (6 for a 24-layer qwen2), not one per leaf (290) — the chip's compile
+    time scales with the number of kernel call sites.  Only for ``fn``s
+    that are exact per unit (integer selection, gathers): a vmapped float
+    reduction may round differently from the unbatched one."""
+    groups: dict = {}
+    for i, (u, s) in enumerate(zip(units, statics)):
+        groups.setdefault((u.shape, jnp.dtype(u.dtype).name, s), []).append(i)
+    out = [None] * len(units)
+    for (_, _, s), members in groups.items():
+        if len(members) == 1:
+            out[members[0]] = fn(units[members[0]], s)
+            continue
+        res = jax.vmap(lambda u: fn(u, s))(
+            jnp.stack([units[i] for i in members]))
+        for j, i in enumerate(members):
+            out[i] = jax.tree_util.tree_map(lambda r: r[j], res)
+    return out
+
+
 def _sparse_report_from_support(leaves, supports, scope: str) -> BitsReport:
     """The TopK transform's bit accounting, from the fused kernels' support
     masks — per leaf and in the leaf order, replicating
@@ -335,14 +359,11 @@ def encode(comp: Optional[Compressor], tree: PyTree,
         # Fused select+pack: per unit, one threshold select + one streaming
         # compaction emits the (idx, vals) slots directly — the masked tree
         # is never materialised; the report comes from the support masks.
-        caps, data, sups = [], [], []
-        for u in units:
-            cap = comp._k(u.size)
-            idx, vals, support = kops.topk_slots(u, cap, cap)
-            data.append((idx, vals))
-            caps.append(cap)
-            sups.append(support)
-        data = tuple(data)
+        caps = [comp._k(u.size) for u in units]
+        slots = _by_shape(lambda u, cap: kops.topk_slots(u, cap, cap),
+                          units, caps)
+        data = tuple((idx, vals) for idx, vals, _ in slots)
+        sups = [support for _, _, support in slots]
         report = _sparse_report_from_support(leaves, sups, scope)
         return Payload(data, mkspec(data, caps=tuple(caps))), report
 

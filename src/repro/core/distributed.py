@@ -35,7 +35,6 @@ sampling makes shard contributions disjoint.
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
@@ -44,16 +43,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.clients import ClientAxisCtx, per_client
 
-try:  # jax >= 0.6 exports shard_map at the top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# older jax wants check_rep=False for axis_index-based slicing; the kwarg
-# was renamed/retired in newer releases, so pass it only when accepted.
-_SM_KWARGS = ({"check_rep": False}
-              if "check_rep" in inspect.signature(_shard_map).parameters
-              else {})
+# Every ``shard_map`` region here runs with ``check_vma=False``.  Each takes
+# replicated inputs and makes them per-shard itself (``axis_index`` slicing
+# of the client axis, shard-local wire buffers), then reassembles its
+# outputs with ``psum``/``all_gather``.  The varying-manual-axes check would
+# type each such value as varying over the mesh axis and reject every
+# ``lax.scan`` whose carry starts replicated and turns varying in the body
+# (the local phase of all five rounds); the outputs are replicated by
+# construction, which the mesh tests pin against the unsharded rounds.
 
 PyTree = Any
 
@@ -203,8 +200,8 @@ class ModelShardCtx(ClientAxisCtx):
     def _manual(self, fn, in_specs, out_specs):
         """Run ``fn`` in a fully-manual ``shard_map`` region over the whole
         mesh (the only non-GSPMD islands of the composed regime)."""
-        return _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, **_SM_KWARGS)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _leaf_model_dims(self, flat):
         """Per-leaf sharded-dim index from the path rules (None when the
@@ -473,8 +470,8 @@ def shard_round(round_impl: Callable, mesh: Mesh, clients_per_round: int,
     def run(state, key):
         return round_impl(state, key, ctx=ctx)
 
-    return _shard_map(run, mesh=mesh, in_specs=(P(), P()),
-                      out_specs=(P(), P()), **_SM_KWARGS)
+    return jax.shard_map(run, mesh=mesh, in_specs=(P(), P()),
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def usable_shard_counts(clients_per_round: int,

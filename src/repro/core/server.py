@@ -26,6 +26,8 @@ class History:
     total_bits: list = dataclasses.field(default_factory=list)
     wall_s: list = dataclasses.field(default_factory=list)
     sim_time: list = dataclasses.field(default_factory=list)  # cumulative
+    # every round's metrics dict (host values), fused chunks included
+    round_metrics: list = dataclasses.field(default_factory=list)
     final_params: Optional[Any] = None  # set by run_federated on completion
 
     @property
@@ -33,10 +35,12 @@ class History:
         return max(self.test_acc) if self.test_acc else float("nan")
 
     def as_dict(self) -> dict:
-        # json-friendly view: the metric curves, without the params pytree
-        # (not asdict(), which would deep-copy the params just to drop them)
+        # json-friendly view: the eval-point curves, without the params
+        # pytree or the per-round array dicts (not asdict(), which would
+        # deep-copy the params just to drop them)
         return {f.name: list(getattr(self, f.name))
-                for f in dataclasses.fields(self) if f.name != "final_params"}
+                for f in dataclasses.fields(self)
+                if f.name not in ("final_params", "round_metrics")}
 
 
 def make_eval_fn(apply_fn: Callable, x_test: jax.Array, y_test: jax.Array,
@@ -129,8 +133,9 @@ def run_federated(
         n = stop - r + 1
         if fused and n > 1:
             state, chunk = algorithm.run_rounds(state, key, n)
-            for _ in range(n):          # stay on the host loop's key chain
+            for i in range(n):          # stay on the host loop's key chain
                 key, _ = jax.random.split(key)
+                hist.round_metrics.append({k: v[i] for k, v in chunk.items()})
             # last round's values; per-client vectors keep their axis
             metrics = {k: (v[-1] if v.ndim > 1 else float(v[-1]))
                        for k, v in chunk.items()}
@@ -140,6 +145,7 @@ def run_federated(
             for _ in range(n):
                 key, sub = jax.random.split(key)
                 state, metrics = algorithm.round(state, sub)
+                hist.round_metrics.append(metrics)
                 sim_t += metrics.get("sim_time", 0.0)
         r = stop + 1
         if is_eval_round(stop):

@@ -10,9 +10,13 @@ elementwise shift/mask/reduce streams — VPU-only, one read + one write of
 ISSUE's uplink path needs).  Matches :func:`repro.kernels.ref.pack_codes`
 bit-for-bit.
 
-Tiling: codes stream through VMEM in (8, 128) blocks = 4 lane-groups of 32
-per sublane row; the word block is the matching (8, 4*b) slab, so the
-flattened output is word-index-major exactly like the reference layout.
+Tiling: codes stream through VMEM in (rows, 128) blocks
+(:mod:`repro.kernels.tiling`) = 4 lane-groups of 32 per sublane row; the
+word block is the matching (rows, 4*b) slab, so the flattened output is
+word-index-major exactly like the reference layout.  Words are built and
+summed as int32 (the chip's vector unit has no unsigned reductions; a sum
+of distinct powers of two wraps to the same bits) and reinterpreted as
+uint32 outside the kernel.
 """
 
 from __future__ import annotations
@@ -23,39 +27,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_BLOCK_ROWS = 8
-_BLOCK_COLS = 128
-_BLOCK = _BLOCK_ROWS * _BLOCK_COLS
-_GROUPS = _BLOCK_COLS // 32      # lane-groups of 32 per sublane row
+from repro.kernels import tiling
+
+GROUPS = tiling.LANES // 32      # lane-groups of 32 per sublane row
+
+
+def pack_block(c: jax.Array, b: int) -> jax.Array:
+    """Bit-plane pack a (rows, 128) int32 code block into (rows, 4*b) int32
+    words: column ``g*b + t`` holds bit ``t`` of lane-group ``g``."""
+    rows = c.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 32), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, GROUPS * b), 1)
+    out = jnp.zeros((rows, GROUPS * b), jnp.int32)
+    for g in range(GROUPS):
+        seg = c[:, g * 32:(g + 1) * 32]                  # (rows, 32)
+        for t in range(b):
+            bits = ((seg >> t) & 1) << lane
+            word = jnp.sum(bits, axis=1, keepdims=True)  # (rows, 1)
+            out = jnp.where(col == g * b + t, word, out)
+    return out
 
 
 def _pack_kernel(codes_ref, out_ref, *, b: int):
-    c = codes_ref[...]                                   # (8, 128) uint32
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (_BLOCK_ROWS, 32), 1)
-    cols = []
-    for g in range(_GROUPS):
-        seg = c[:, g * 32:(g + 1) * 32]                  # (8, 32)
-        for t in range(b):
-            bits = ((seg >> jnp.uint32(t)) & jnp.uint32(1)) << lane
-            cols.append(jnp.sum(bits, axis=1))           # (8,)
-    out_ref[...] = jnp.stack(cols, axis=1)               # (8, 4*b)
+    out_ref[...] = pack_block(codes_ref[...], b)
 
 
 def _unpack_kernel(words_ref, out_ref, *, b: int):
-    w = words_ref[...]                                   # (8, 4*b) uint32
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (_BLOCK_ROWS, 32), 1)
+    w = words_ref[...]                                   # (rows, 4*b) uint32
+    lane = jax.lax.broadcasted_iota(jnp.uint32, (w.shape[0], 32), 1)
     segs = []
-    for g in range(_GROUPS):
-        acc = jnp.zeros((_BLOCK_ROWS, 32), jnp.uint32)
+    for g in range(GROUPS):
+        acc = jnp.zeros((w.shape[0], 32), jnp.uint32)
         for t in range(b):
-            word = w[:, g * b + t][:, None]              # (8, 1)
+            word = w[:, g * b + t][:, None]              # (rows, 1)
             acc += ((word >> lane) & jnp.uint32(1)) << jnp.uint32(t)
         segs.append(acc)
-    out_ref[...] = jnp.concatenate(segs, axis=1)         # (8, 128)
-
-
-def _rows_for(n: int) -> int:
-    return pl.cdiv(max(int(n), 1), _BLOCK) * _BLOCK_ROWS
+    out_ref[...] = jnp.concatenate(segs, axis=1)         # (rows, 128)
 
 
 @functools.partial(jax.jit, static_argnames=("b", "interpret"))
@@ -67,20 +74,21 @@ def pack_codes(codes: jax.Array, b: int, *,
     b = int(b)
     if not (1 <= b <= 32):
         raise ValueError(f"code width must be in [1, 32], got {b}")
-    n = codes.size
-    n32 = pl.cdiv(n, 32)
-    rows = _rows_for(n)
-    c2d = jnp.pad(codes.astype(jnp.uint32),
-                  (0, rows * _BLOCK_COLS - n)).reshape(rows, _BLOCK_COLS)
+    rows = tiling.block_rows(codes.size)
+    c2d = tiling.to_slab(
+        jax.lax.bitcast_convert_type(codes.astype(jnp.uint32), jnp.int32),
+        rows, jnp.int32)
     words2d = pl.pallas_call(
         functools.partial(_pack_kernel, b=b),
-        grid=(rows // _BLOCK_ROWS,),
-        in_specs=[pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((_BLOCK_ROWS, _GROUPS * b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _GROUPS * b), jnp.uint32),
+        grid=(c2d.shape[0] // rows,),
+        in_specs=[tiling.block_spec(rows)],
+        out_specs=tiling.block_spec(rows, GROUPS * b),
+        out_shape=jax.ShapeDtypeStruct((c2d.shape[0], GROUPS * b),
+                                       jnp.int32),
         interpret=interpret,
     )(c2d)
-    return words2d.reshape(-1)[: n32 * b]
+    words = jax.lax.bitcast_convert_type(words2d, jnp.uint32)
+    return words.reshape(-1)[: pl.cdiv(codes.size, 32) * b]
 
 
 @functools.partial(jax.jit, static_argnames=("b", "n", "interpret"))
@@ -94,16 +102,17 @@ def unpack_codes(words: jax.Array, b: int, n: int, *,
     if words.size != n32 * b:
         raise ValueError(
             f"expected {n32 * b} words for n={n}, b={b}, got {words.size}")
-    rows = _rows_for(n)
+    rows = tiling.block_rows(n)
+    slab_rows = pl.cdiv(max(n, 1), rows * tiling.LANES) * rows
     w2d = jnp.pad(words.astype(jnp.uint32),
-                  (0, rows * _GROUPS * b - words.size)
-                  ).reshape(rows, _GROUPS * b)
+                  (0, slab_rows * GROUPS * b - words.size)
+                  ).reshape(slab_rows, GROUPS * b)
     codes2d = pl.pallas_call(
         functools.partial(_unpack_kernel, b=b),
-        grid=(rows // _BLOCK_ROWS,),
-        in_specs=[pl.BlockSpec((_BLOCK_ROWS, _GROUPS * b), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _BLOCK_COLS), jnp.uint32),
+        grid=(slab_rows // rows,),
+        in_specs=[tiling.block_spec(rows, GROUPS * b)],
+        out_specs=tiling.block_spec(rows),
+        out_shape=jax.ShapeDtypeStruct((slab_rows, tiling.LANES), jnp.uint32),
         interpret=interpret,
     )(w2d)
     return codes2d.reshape(-1)[:n]

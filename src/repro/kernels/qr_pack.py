@@ -3,7 +3,7 @@
 The ``qr`` wire codec ships one (1+r)-bit code per scalar: a sign bit plus
 the quantizer's stochastic level.  PR 5 materialised the dense uint32 code
 array and re-read it in a second pack pass; this kernel computes the codes
-*and* packs them into uint32 words in one VMEM pass per (8, 128) block —
+*and* packs them into uint32 words in one VMEM pass per (rows, 128) block —
 the dense code array never touches HBM, so the encode streams ~(1 + b/32)d
 words instead of (2 + b/32)d.
 
@@ -25,36 +25,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_BLOCK_ROWS = 8
-_BLOCK_COLS = 128
-_BLOCK = _BLOCK_ROWS * _BLOCK_COLS
-_GROUPS = _BLOCK_COLS // 32      # lane-groups of 32 per sublane row
+from repro.kernels import tiling
+from repro.kernels.pack_codes import GROUPS, pack_block
 
 
-def _qr_pack_kernel(x_ref, u_ref, norm_ref, out_ref, *, levels: float, b: int):
-    x = x_ref[...]                                       # (8, 128) float32
+def _qr_pack_kernel(norm_ref, x_ref, u_ref, out_ref, *, levels: int, b: int):
+    x = tiling.load_f32(x_ref)                           # (rows, 128)
     norm = norm_ref[0, 0]
     y = jnp.abs(x) / jnp.where(norm > 0, norm, 1.0)
     scaled = levels * y
     lo = jnp.floor(scaled)
     code = lo + (u_ref[...] < scaled - lo).astype(jnp.float32)
-    code = jnp.minimum(code, levels - 1.0)               # saturate top level
-    c = code.astype(jnp.uint32) | jnp.where(
-        x < 0, jnp.uint32(levels), jnp.uint32(0))        # sign bit << r
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (_BLOCK_ROWS, 32), 1)
-    cols = []
-    for g in range(_GROUPS):
-        seg = c[:, g * 32:(g + 1) * 32]                  # (8, 32)
-        for t in range(b):
-            bits = ((seg >> jnp.uint32(t)) & jnp.uint32(1)) << lane
-            cols.append(jnp.sum(bits, axis=1))           # (8,)
-    out_ref[...] = jnp.stack(cols, axis=1)               # (8, 4*b)
-
-
-def _pad_to_block(x: jax.Array):
-    n = x.size
-    padded = pl.cdiv(n, _BLOCK) * _BLOCK
-    return jnp.pad(x, (0, padded - n)).reshape(-1, _BLOCK_COLS)
+    code = jnp.minimum(code, levels - 1.0).astype(jnp.int32)  # saturate
+    code = code + jnp.where(x < 0, levels, 0)            # sign bit << r
+    out_ref[...] = pack_block(code, b)
 
 
 @functools.partial(jax.jit, static_argnames=("r", "interpret"))
@@ -72,19 +56,18 @@ def quantize_pack_with_uniforms(x: jax.Array, r: int, u: jax.Array,
         raise ValueError(f"expects 1-D input, got {x.shape}")
     r = int(r)
     b = 1 + r
-    n = x.size
-    n32 = pl.cdiv(n, 32)
-    x2d = _pad_to_block(x.astype(jnp.float32))
-    u2d = _pad_to_block(u.astype(jnp.float32))
-    rows = x2d.shape[0]
+    rows = tiling.block_rows(x.size)
+    x2d = tiling.to_slab(x, rows)
+    u2d = tiling.to_slab(u, rows, jnp.float32)
     words2d = pl.pallas_call(
-        functools.partial(_qr_pack_kernel, levels=float(2 ** r), b=b),
-        grid=(rows // _BLOCK_ROWS,),
-        in_specs=[pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0)),
-                  pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((_BLOCK_ROWS, _GROUPS * b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, _GROUPS * b), jnp.uint32),
+        functools.partial(_qr_pack_kernel, levels=2 ** r, b=b),
+        grid=(x2d.shape[0] // rows,),
+        in_specs=[tiling.SMEM_SPEC, tiling.block_spec(rows),
+                  tiling.block_spec(rows)],
+        out_specs=tiling.block_spec(rows, GROUPS * b),
+        out_shape=jax.ShapeDtypeStruct((x2d.shape[0], GROUPS * b),
+                                       jnp.int32),
         interpret=interpret,
-    )(x2d, u2d, jnp.asarray(norm, jnp.float32).reshape(1, 1))
-    return words2d.reshape(-1)[: n32 * b]
+    )(jnp.asarray(norm, jnp.float32).reshape(1, 1), x2d, u2d)
+    words = jax.lax.bitcast_convert_type(words2d, jnp.uint32)
+    return words.reshape(-1)[: pl.cdiv(x.size, 32) * b]

@@ -3,7 +3,8 @@
 Two streaming passes, both VMEM-tiled:
 
   1. sum-of-squares reduction (for the per-vector l2 norm), accumulated
-     across the sequential TPU grid;
+     per (sublane, lane) slot across the sequential TPU grid and summed
+     once outside;
   2. elementwise stochastic rounding onto the 2^r-level grid:
      out_i = ||x|| * sgn(x_i) * (floor(L*y_i) + [u_i < frac]) / L,
      y_i = |x_i| / ||x||, L = 2^r.
@@ -21,25 +22,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_BLOCK_ROWS = 8
-_BLOCK_COLS = 128
-_BLOCK = _BLOCK_ROWS * _BLOCK_COLS
+from repro.kernels import tiling
 
 
-def _sumsq_kernel(x_ref, valid_ref, out_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+def _sumsq_kernel(x_ref, out_ref):
+    """Per-(sublane, lane) partial sums of squares, accumulated across the
+    sequential grid (zero padding adds nothing)."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...]
-    sel = valid_ref[...] != 0
-    out_ref[0, 0] += jnp.sum(jnp.where(sel, x * x, 0.0))
+    x = tiling.load_f32(x_ref)
+    out_ref[...] += tiling.sublane_sum(x * x)
 
 
-def _quant_kernel(x_ref, u_ref, norm_ref, out_ref, *, levels: float):
-    x = x_ref[...]
+def _quant_kernel(norm_ref, x_ref, u_ref, out_ref, *, levels: float):
+    x = tiling.load_f32(x_ref)
     norm = norm_ref[0, 0]
     safe = jnp.where(norm > 0, norm, 1.0)
     y = jnp.abs(x) / safe
@@ -49,19 +47,6 @@ def _quant_kernel(x_ref, u_ref, norm_ref, out_ref, *, levels: float):
     xi = (lo + (u_ref[...] < frac).astype(jnp.float32)) / levels
     out = norm * jnp.sign(x) * xi
     out_ref[...] = jnp.where(norm > 0, out, jnp.zeros_like(out))
-
-
-def _pad_to_block(x: jax.Array):
-    n = x.size
-    padded = pl.cdiv(n, _BLOCK) * _BLOCK
-    return jnp.pad(x, (0, padded - n)).reshape(-1, _BLOCK_COLS)
-
-
-def _block_spec():
-    return pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0))
-
-
-_SCALAR_SPEC = pl.BlockSpec((1, 1), lambda i: (0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -75,22 +60,20 @@ def l2_norm(x: jax.Array, *, interpret: bool = False) -> jax.Array:
     """
     if x.ndim != 1:
         raise ValueError(f"expects 1-D input, got {x.shape}")
-    n = x.size
-    x2d = _pad_to_block(x.astype(jnp.float32))
-    rows = x2d.shape[0]
-    idx = (jax.lax.broadcasted_iota(jnp.int32, (rows, _BLOCK_COLS), 0)
-           * _BLOCK_COLS
-           + jax.lax.broadcasted_iota(jnp.int32, (rows, _BLOCK_COLS), 1))
-    valid = (idx < n).astype(jnp.int32)
-    sumsq = pl.pallas_call(
+    rows = tiling.block_rows(x.size)
+    x2d = tiling.to_slab(x, rows)
+    partial = pl.pallas_call(
         _sumsq_kernel,
-        grid=(rows // _BLOCK_ROWS,),
-        in_specs=[_block_spec(), _block_spec()],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        grid=(x2d.shape[0] // rows,),
+        in_specs=[tiling.block_spec(rows)],
+        out_specs=pl.BlockSpec((tiling.SUBLANES, tiling.LANES),
+                               lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((tiling.SUBLANES, tiling.LANES),
+                                       jnp.float32),
+        compiler_params=tiling.SEQUENTIAL,
         interpret=interpret,
-    )(x2d, valid)
-    return jnp.sqrt(sumsq)[0, 0]
+    )(x2d)
+    return jnp.sqrt(jnp.sum(partial))
 
 
 @functools.partial(jax.jit, static_argnames=("r", "interpret"))
@@ -100,24 +83,20 @@ def quantize_qr_with_uniforms(
     """Q_r(x) on a 1-D vector with uniforms ``u`` in [0,1) of the same shape."""
     if x.ndim != 1:
         raise ValueError(f"expects 1-D input, got {x.shape}")
-    orig_dtype = x.dtype
-    n = x.size
-    xf = x.astype(jnp.float32)
-    x2d = _pad_to_block(xf)
-    u2d = _pad_to_block(u.astype(jnp.float32))
-    rows = x2d.shape[0]
-    grid = rows // _BLOCK_ROWS
+    rows = tiling.block_rows(x.size)
+    x2d = tiling.to_slab(x, rows)
+    u2d = tiling.to_slab(u, rows, jnp.float32)
     norm = l2_norm(x, interpret=interpret).reshape(1, 1)
-
     out2d = pl.pallas_call(
         functools.partial(_quant_kernel, levels=float(2 ** r)),
-        grid=(grid,),
-        in_specs=[_block_spec(), _block_spec(), _SCALAR_SPEC],
-        out_specs=_block_spec(),
-        out_shape=jax.ShapeDtypeStruct((rows, _BLOCK_COLS), jnp.float32),
+        grid=(x2d.shape[0] // rows,),
+        in_specs=[tiling.SMEM_SPEC, tiling.block_spec(rows),
+                  tiling.block_spec(rows)],
+        out_specs=tiling.block_spec(rows),
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
         interpret=interpret,
-    )(x2d, u2d, norm)
-    return out2d.reshape(-1)[:n].astype(orig_dtype)
+    )(norm, x2d, u2d)
+    return out2d.reshape(-1)[:x.size].astype(x.dtype)
 
 
 def quantize_qr(x: jax.Array, r: int, key: jax.Array, *,

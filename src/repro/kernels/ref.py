@@ -35,9 +35,8 @@ def radix_digit_hist(bits: jax.Array, prefix: jax.Array,
     """256-bin int32 histogram of the 8-bit digit at ``shift``, counting
     only elements whose already-decided high bits match ``prefix``.
 
-    One O(n) scatter-add pass over the uint32 magnitude bit patterns —
-    the jnp mirror of the Pallas histogram kernel in
-    :mod:`repro.kernels.topk_compress`.  Integer counts make the histogram
+    One O(n) scatter-add pass over the uint32 magnitude bit patterns.
+    Integer counts make the histogram
     an *exact* ``psum`` reducend: summing per-shard histograms across a
     model-parallel mesh axis yields bit-for-bit the histogram of the
     concatenated vector, which is how the sharded wire path (DESIGN.md §9)
@@ -86,8 +85,8 @@ def topk_threshold_bits(x: jax.Array, k, *, digit_bits: int = 1,
       histograms serialize (EXPERIMENTS.md §Perf: 8-bit digits cost +127%
       on an account-mode round).
     * ``digit_bits=8`` — 4 passes over 256-bin scatter-add histograms
-      (:func:`radix_digit_hist`), the jnp twin of the Pallas kernel in
-      :mod:`repro.kernels.topk_compress`.
+      (:func:`radix_digit_hist`).  The Pallas walk
+      (:mod:`repro.kernels.topk_compress`) counts 4-bit candidates instead.
 
     With ``psum_axis`` (inside ``shard_map``) every per-pass count or
     histogram is ``lax.psum``-ed across that mesh axis, so the walk

@@ -4,21 +4,25 @@ GPU implementations use warp-level radix select in shared memory; the
 TPU-native adaptation here is a *radix threshold select* over magnitude bit
 patterns:
 
-  1. bitcast |x| to uint32 — for finite non-negative floats the integer order
-     equals the float order, so the k-th largest magnitude can be found on
-     bit patterns;
-  2. four sequential 256-bin histogram passes (8 bits per pass, MSB first),
-     each a ``pl.pallas_call`` that tiles x through VMEM and accumulates the
-     histogram across the (sequential) TPU grid;
-  3. the traced driver walks each histogram to fix one radix digit per pass,
-     yielding the exact bit pattern t of the k-th largest magnitude;
+  1. |x| as an int32 bit pattern — for non-negative floats the integer
+     order equals the float order, so the k-th largest magnitude can be
+     found on bit patterns (bit 31 is never set);
+  2. eight MSB-first passes fix the threshold 4 bits at a time (3 bits in
+     the first pass, which covers bits 30..28).  Each pass is one
+     ``pl.pallas_call`` that streams x through VMEM and counts, for each of
+     15 candidates ``t | c << shift``, the entries with ``bits >= cand``.
+     Counts accumulate as int32 per (sublane, lane) slot across the
+     sequential grid and are summed outside: exact to 2^31 entries, far
+     past float32's 2^24;
+  3. the traced driver keeps, per pass, the largest candidate whose count
+     is still ``>= k``, which yields the exact bit pattern t of the k-th
+     largest magnitude — the largest ``t`` with ``count(bits >= t) >= k``;
   4. one elementwise masking pass keeps entries with |x| >= t.
 
-All passes are memory-bound streaming ops: 4 histogram reads + 1 masked
-read/write = ~6d traffic versus O(d log d) for a sort.  Histogramming is
-VPU-friendly (one-hot compare + reduce, no MXU needed).  Matches the
-threshold semantics of :func:`repro.kernels.ref.topk_mask` exactly
-(ties at the threshold are kept).
+Counting ``>=`` candidates directly (a cumulative histogram) needs no
+per-digit bucket mask, no one-hot reshape and no remaining-k bookkeeping.
+Matches the threshold semantics of :func:`repro.kernels.ref.topk_mask`
+exactly (ties at the threshold are kept).
 """
 
 from __future__ import annotations
@@ -29,65 +33,73 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Block: 8 sublanes x 128 lanes per grid step.
-_BLOCK_ROWS = 8
-_BLOCK_COLS = 128
-_BLOCK = _BLOCK_ROWS * _BLOCK_COLS
-_NBINS = 256
+from repro.kernels import tiling
+
+#: Candidates counted per pass (4-bit digits: 1..15).
+NCAND = 15
+#: (shift, digit bits) of the MSB-first walk over the 31 magnitude bits.
+DIGITS = ((28, 3),) + tuple((s, 4) for s in (24, 20, 16, 12, 8, 4, 0))
+
+_I32_MAX = 0x7FFFFFFF
 
 
-def _hist_kernel(bits_ref, valid_ref, prefix_ref, hist_ref, *, shift: int):
-    """Accumulate the 256-bin histogram of the current radix digit.
+def _count_kernel(cand_ref, x_ref, out_ref):
+    """out[c] += per-slot count of ``bits >= cand[c]`` over this block.
 
-    bits_ref:   (BLOCK_ROWS, BLOCK_COLS) uint32 magnitude bit patterns
-    valid_ref:  (BLOCK_ROWS, BLOCK_COLS) int32 1/0 validity mask (padding)
-    prefix_ref: (1, 1) uint32 — radix digits already decided (high bits)
-    hist_ref:   (1, NBINS) float32 output, accumulated across the grid
+    cand_ref: (1, NCAND) int32 candidates in SMEM
+    x_ref:    (rows, 128) float32 or bfloat16 block
+    out_ref:  (NCAND, 8, 128) int32, accumulated across the grid
     """
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    bits = bits_ref[...]
-    valid = valid_ref[...] != 0
-    prefix = prefix_ref[0, 0]
-    # Only elements whose already-decided high bits match the prefix count.
-    if shift + 8 < 32:
-        high_mask = jnp.uint32(0xFFFFFFFF << (shift + 8) & 0xFFFFFFFF)
-    else:
-        high_mask = jnp.uint32(0)
-    in_bucket = (bits & high_mask) == (prefix & high_mask)
-    digit = ((bits >> shift) & jnp.uint32(0xFF)).astype(jnp.int32)
-    sel = in_bucket & valid
-    # One-hot accumulate: (BLOCK, 1) digit vs (1, NBINS) bins.
-    onehot = (digit.reshape(-1, 1)
-              == jax.lax.broadcasted_iota(jnp.int32, (1, _NBINS), 1))
-    contrib = jnp.sum(
-        jnp.where(sel.reshape(-1, 1), onehot.astype(jnp.float32), 0.0),
-        axis=0, keepdims=True)
-    hist_ref[...] += contrib
+    bits = tiling.magnitude_bits(tiling.load_f32(x_ref))
+    for c in range(NCAND):
+        hit = (bits >= cand_ref[0, c]).astype(jnp.int32)
+        out_ref[c] += tiling.sublane_sum(hit)
 
 
-def _mask_kernel(bits_ref, x_ref, thr_ref, out_ref):
-    """out = where(bits >= t, x, 0) — the final masking pass."""
+def _mask_kernel(thr_ref, x_ref, out_ref):
+    """out = where(|x| >= t, x, 0) — the final masking pass."""
     t = thr_ref[0, 0]
-    out_ref[...] = jnp.where(bits_ref[...] >= t, x_ref[...],
-                             jnp.zeros_like(x_ref[...]))
+    x = tiling.load_f32(x_ref)
+    keep = (tiling.magnitude_bits(x) >= t) & (t >= 0)
+    out_ref[...] = jnp.where(keep, x, jnp.zeros_like(x))
 
 
-def _pad_to_block(x: jax.Array):
-    n = x.size
-    padded = pl.cdiv(n, _BLOCK) * _BLOCK
-    return jnp.pad(x, (0, padded - n)).reshape(-1, _BLOCK_COLS)
+def _counts(x2d: jax.Array, cands: jax.Array, rows: int,
+            interpret: bool) -> jax.Array:
+    out = pl.pallas_call(
+        _count_kernel,
+        grid=(x2d.shape[0] // rows,),
+        in_specs=[tiling.SMEM_SPEC, tiling.block_spec(rows)],
+        out_specs=pl.BlockSpec((NCAND, tiling.SUBLANES, tiling.LANES),
+                               lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (NCAND, tiling.SUBLANES, tiling.LANES), jnp.int32),
+        compiler_params=tiling.SEQUENTIAL,
+        interpret=interpret,
+    )(cands, x2d)
+    return out.sum(axis=(1, 2))
 
 
-_SCALAR_SPEC = pl.BlockSpec((1, 1), lambda i: (0, 0))
-
-
-def _block_spec():
-    return pl.BlockSpec((_BLOCK_ROWS, _BLOCK_COLS), lambda i: (i, 0))
+def _walk(x2d: jax.Array, k: int, rows: int, interpret: bool) -> jax.Array:
+    """The radix walk: int32 bit pattern of the k-th largest magnitude."""
+    t = jnp.zeros((), jnp.int32)
+    kk = jnp.int32(k)
+    for shift, width in DIGITS:
+        ncand = 2 ** width - 1
+        offs = jnp.asarray([c << shift if c <= ncand else 0
+                            for c in range(1, NCAND + 1)], jnp.int32)
+        live = jnp.arange(1, NCAND + 1) <= ncand
+        cands = jnp.where(live, t + offs, _I32_MAX).reshape(1, NCAND)
+        counts = _counts(x2d, cands, rows, interpret)
+        # counts are non-increasing in the candidate; the digit is the
+        # number of candidates that still keep >= k entries
+        digit = jnp.sum(((counts >= kk) & live).astype(jnp.int32))
+        t = t + (digit << shift)
+    return t
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -97,7 +109,7 @@ def threshold_bits(x: jax.Array, k: int, *,
 
     Steps 1-3 of the module docstring, exposed on their own so the fused
     select+pack kernels (:mod:`repro.kernels.select_slots`) can reuse the
-    threshold without re-deriving it.  Same value as the jnp binary search
+    threshold without re-deriving it.  Same value as the jnp walk
     (:func:`repro.kernels.ref.topk_threshold_bits`): the exact bit pattern
     of the k-th largest magnitude, ties included.  ``k >= n`` returns 0
     (every entry compares >= the threshold); ``k == 0`` returns the
@@ -110,39 +122,9 @@ def threshold_bits(x: jax.Array, k: int, *,
         return jnp.zeros((), jnp.uint32)
     if k <= 0:
         return jnp.full((), 0xFFFFFFFF, jnp.uint32)
-    n = x.size
-    xf = x.astype(jnp.float32)
-    bits2d = _pad_to_block(jnp.abs(xf).view(jnp.uint32))
-    rows = bits2d.shape[0]
-    idx = (jax.lax.broadcasted_iota(jnp.int32, (rows, _BLOCK_COLS), 0)
-           * _BLOCK_COLS
-           + jax.lax.broadcasted_iota(jnp.int32, (rows, _BLOCK_COLS), 1))
-    valid = (idx < n).astype(jnp.int32)
-    grid = rows // _BLOCK_ROWS
-
-    def run_hist(prefix: jax.Array, shift: int) -> jax.Array:
-        return pl.pallas_call(
-            functools.partial(_hist_kernel, shift=shift),
-            grid=(grid,),
-            in_specs=[_block_spec(), _block_spec(), _SCALAR_SPEC],
-            out_specs=pl.BlockSpec((1, _NBINS), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, _NBINS), jnp.float32),
-            interpret=interpret,
-        )(bits2d, valid, prefix.reshape(1, 1))[0]
-
-    prefix = jnp.zeros((), jnp.uint32)
-    k_rem = jnp.asarray(k, jnp.float32)
-    for shift in (24, 16, 8, 0):
-        hist = run_hist(prefix, shift)                       # (256,)
-        ge = jnp.cumsum(hist[::-1])[::-1]                    # count(digit >= j)
-        # ge is non-increasing; keep the largest digit with ge >= k_rem.
-        sel = ge >= k_rem
-        digit = jnp.clip(jnp.sum(sel.astype(jnp.int32)) - 1, 0, 255)
-        # Elements with a strictly larger digit are all above the threshold.
-        gt = jnp.where(digit < 255, ge[jnp.clip(digit + 1, 0, 255)], 0.0)
-        k_rem = k_rem - gt
-        prefix = prefix | (digit.astype(jnp.uint32) << shift)
-    return prefix
+    rows = tiling.block_rows(x.size)
+    return _walk(tiling.to_slab(x, rows), k, rows,
+                 interpret).astype(jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -153,21 +135,15 @@ def topk_mask(x: jax.Array, k: int, *, interpret: bool = False) -> jax.Array:
     k = int(k)
     if k >= x.size:
         return x
-    orig_dtype = x.dtype
-    xf = x.astype(jnp.float32)
-    n = x.size
-    bits2d = _pad_to_block(jnp.abs(xf).view(jnp.uint32))
-    x2d = _pad_to_block(xf)
-    rows = bits2d.shape[0]
-    grid = rows // _BLOCK_ROWS
-    t = threshold_bits(x, k, interpret=interpret)
-
+    rows = tiling.block_rows(x.size)
+    x2d = tiling.to_slab(x, rows)
+    t = tiling.threshold_i32(threshold_bits(x, k, interpret=interpret))
     out2d = pl.pallas_call(
         _mask_kernel,
-        grid=(grid,),
-        in_specs=[_block_spec(), _block_spec(), _SCALAR_SPEC],
-        out_specs=_block_spec(),
-        out_shape=jax.ShapeDtypeStruct((rows, _BLOCK_COLS), jnp.float32),
+        grid=(x2d.shape[0] // rows,),
+        in_specs=[tiling.SMEM_SPEC, tiling.block_spec(rows)],
+        out_specs=tiling.block_spec(rows),
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
         interpret=interpret,
-    )(bits2d, x2d, t.reshape(1, 1))
-    return out2d.reshape(-1)[:n].astype(orig_dtype)
+    )(t, x2d)
+    return out2d.reshape(-1)[:x.size].astype(x.dtype)

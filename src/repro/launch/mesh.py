@@ -4,23 +4,34 @@ Defined as functions (never module-level constants) so importing this module
 never touches jax device state.  The dry-run launcher sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
 import to obtain placeholder devices.
+
+Every mesh axis is ``AxisType.Auto``: the programs place parameters with
+``NamedSharding``s and pin activations with ``with_sharding_constraint``
+(``P.UNCONSTRAINED`` for dims left to GSPMD), which is the automatic-axes
+regime.  ``jax.make_mesh`` would otherwise pick explicit axes, whose
+sharding-in-types rules those constraints were not written for.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """v5e production mesh: 16x16 per pod; 2 pods when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Degenerate 1x1 mesh for CPU smoke runs of the pjit code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_client_mesh(n_shards: int | None = None, *, data: int = 1,
@@ -46,9 +57,8 @@ def make_client_mesh(n_shards: int | None = None, *, data: int = 1,
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
     if data == 1 and model == 1:
-        return jax.make_mesh((n_shards,), ("clients",))
-    mesh = jax.make_mesh((n_shards, data, model),
-                         ("clients", "data", "model"))
+        return _mesh((n_shards,), ("clients",))
+    mesh = _mesh((n_shards, data, model), ("clients", "data", "model"))
     if config is not None and model > 1:
         from repro.core.distributed import validate_model_axis
         validate_model_axis(mesh, config)

@@ -316,14 +316,23 @@ class TestLoCoDL:
         assert changed_h.sum() <= agg
 
     def test_loss_decreases(self):
-        alg = build("locodl", downlink="account", down_comp=QuantQr(r=8))
-        st, key = alg.init(P0), jax.random.PRNGKey(0)
-        losses = []
-        for _ in range(30):
-            key, sub = jax.random.split(key)
-            st, m = alg.round(st, sub)
-            losses.append(float(m["train_loss"]))
-        assert np.mean(losses[-5:]) < 0.5 * np.mean(losses[:5])
+        # last-5 / first-5 mean loss ratio after 30 rounds.  One key is a
+        # noisy witness: over keys 0-9 the ratio spans 0.27-0.82 (mean
+        # 0.43) under jax 0.9's random stream, and a single-key "halves"
+        # bound flips with the stream.  So: every key must decrease the
+        # loss, and the mean over keys 0-3 (0.35 today) must halve it.
+        ratios = []
+        for seed in range(4):
+            alg = build("locodl", downlink="account", down_comp=QuantQr(r=8))
+            st, key = alg.init(P0), jax.random.PRNGKey(seed)
+            losses = []
+            for _ in range(30):
+                key, sub = jax.random.split(key)
+                st, m = alg.round(st, sub)
+                losses.append(float(m["train_loss"]))
+            ratios.append(np.mean(losses[-5:]) / np.mean(losses[:5]))
+        assert max(ratios) < 0.9, ratios
+        assert np.mean(ratios) < 0.5, ratios
 
 
 # --------------------------------------------------------------------------- #

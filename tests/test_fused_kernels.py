@@ -168,6 +168,31 @@ class TestCompactSlots:
         assert (idx_r == idx_p.astype(jnp.uint32)).all()
         assert (vals_r == vals_p).all()
 
+    def test_multi_block_parity(self):
+        # 2.5 grid blocks of 65,536 entries: survivors cross output blocks
+        # mid-grid and the threshold walk accumulates over several steps
+        n = 5 * 65536 // 2 + 77
+        x = _vec(n, seed=13)
+        k = n // 7
+        t = tc.threshold_bits(x, k, interpret=True)
+        assert int(t) == int(ref.topk_threshold_bits(x, k))
+        idx_r, vals_r, _ = ref.topk_slots(x, k, k)
+        idx_p, vals_p = sel.compact_slots(x, t, k, interpret=True)
+        assert (idx_r == idx_p.astype(jnp.uint32)).all()
+        assert (vals_r == vals_p).all()
+
+    def test_last_block_spill_is_flushed(self):
+        # the last grid block's survivors straddle an output block: what it
+        # spills past that block must still reach the output
+        e = 512 * 128
+        x = jnp.zeros(2 * e, jnp.float32).at[:e // 2].set(2.0).at[e:].set(1.0)
+        cap = e // 2 + e
+        t = jax.lax.bitcast_convert_type(jnp.float32(1.0), jnp.uint32)
+        idx_r, vals_r, _ = ref.topk_slots(x, cap, cap)
+        idx_p, vals_p = sel.compact_slots(x, t, cap, interpret=True)
+        assert (idx_r == idx_p.astype(jnp.uint32)).all()
+        assert (vals_r == vals_p).all()
+
 
 class TestQrPack:
     @pytest.mark.parametrize("n", [33, 1024, 1030, 5000])
@@ -209,7 +234,8 @@ class TestQrPack:
 
 
 class TestCompactCodeSlots:
-    @pytest.mark.parametrize("n", [67, 1030, 3000])
+    # 163,917 entries: 2.5 grid blocks, so slots cross output blocks
+    @pytest.mark.parametrize("n", [67, 1030, 3000, 163917])
     @pytest.mark.parametrize("r", [1, 4, wire.MAX_R])
     def test_parity(self, n, r):
         x, u = _vec(n, seed=n + 5), _uni(n, seed=n + 6)
