@@ -17,7 +17,10 @@ then provides the two execution modes:
   host loop's ``key, sub = jax.random.split(key)``.
 
 Both record into ``self.meter`` (a :class:`repro.core.comm.CommMeter`), so
-histories and bits-axes are identical whichever driver ran.
+histories and bits-axes are identical whichever driver ran.  Each driver
+opens the host spans of ``repro.core.spans`` around its steps (cohort
+planning, dispatch, the metrics fetch that waits for the device), so a
+profiler trace names the host's part of every idle gap.
 
 ``set_policy`` binds one of the three aggregation policies (DESIGN.md §7:
 ``sync`` / ``semi_sync(K)`` / ``async_buffered``); the round
@@ -37,6 +40,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
+
+from repro.core.spans import (
+    ENGINE_DISPATCH, ENGINE_FETCH_METRICS, ENGINE_PLAN_COHORTS, host_span)
 
 PyTree = Any
 
@@ -299,13 +305,16 @@ class RoundEngine:
         metrics (e.g. ``client_uplink_bits``, DESIGN.md §5) as numpy
         arrays.
         """
-        self._plan_cohorts(state, key, 1, stepped=True)
-        state, metrics = self._round(state, key)
-        out = {k: (np.asarray(v) if getattr(v, "ndim", 0) else float(v))
-               for k, v in metrics.items()}
-        self.meter.record_round(
-            uplink_bits=out.get("uplink_bits", 0.0),
-            downlink_bits=out.get("downlink_bits", 0.0))
+        with host_span(ENGINE_PLAN_COHORTS):
+            self._plan_cohorts(state, key, 1, stepped=True)
+        with host_span(ENGINE_DISPATCH):
+            state, metrics = self._round(state, key)
+        with host_span(ENGINE_FETCH_METRICS):
+            out = {k: (np.asarray(v) if getattr(v, "ndim", 0) else float(v))
+                   for k, v in metrics.items()}
+            self.meter.record_round(
+                uplink_bits=out.get("uplink_bits", 0.0),
+                downlink_bits=out.get("downlink_bits", 0.0))
         return state, out
 
     # ------------------------------------------------------------------ #
@@ -343,10 +352,14 @@ class RoundEngine:
         num_rounds = int(num_rounds)
         if num_rounds <= 0:
             raise ValueError("num_rounds must be positive")
-        self._plan_cohorts(state, key, num_rounds)
-        state, metrics = self._fused(num_rounds)(state, key)
-        self.meter.record_rounds(
-            uplink_bits=metrics.get("uplink_bits"),
-            downlink_bits=metrics.get("downlink_bits"),
-            num_rounds=num_rounds)
-        return state, {k: np.asarray(v) for k, v in metrics.items()}
+        with host_span(ENGINE_PLAN_COHORTS):
+            self._plan_cohorts(state, key, num_rounds)
+        with host_span(ENGINE_DISPATCH):
+            state, metrics = self._fused(num_rounds)(state, key)
+        with host_span(ENGINE_FETCH_METRICS):
+            self.meter.record_rounds(
+                uplink_bits=metrics.get("uplink_bits"),
+                downlink_bits=metrics.get("downlink_bits"),
+                num_rounds=num_rounds)
+            metrics = {k: np.asarray(v) for k, v in metrics.items()}
+        return state, metrics

@@ -48,6 +48,9 @@ from repro.core.clients import (
     validate_schedule, vmap_compress)
 from repro.core.engine import RoundEngine
 from repro.core.fed_data import FederatedData
+from repro.core.spans import (
+    ROUND_AGGREGATE, ROUND_DOWNLINK, ROUND_ENCODE, ROUND_LOCAL_PHASE,
+    ROUND_SAMPLE, ROUND_STATE_GATHER, ROUND_STATE_UPDATE, span)
 
 PyTree = Any
 LossFn = Callable[[PyTree, jax.Array, jax.Array], jax.Array]
@@ -201,30 +204,33 @@ class FedComLoc(RoundEngine):
             k_dl = None
         s = cfg.clients_per_round
         s_loc = ctx.local_count(s)
-        # §11: availability-aware cohort sampling (the neutral path is the
-        # historical uniform choice, same key consumption)
-        clients_full, avail_full = sched.sample_cohort(
-            k_sample, s, state.round)
-        num_steps = self._num_local_steps(k_steps)
-        # Client-heterogeneity layer (DESIGN.md §5): per-client step counts
-        # (straggler deadline), participation mask, compressor overrides.
-        # The full (s,) plan is computed replicated (metrics use it); the
-        # per-client compute below runs on this shard's slice (§6).
-        plan = sched.plan(clients_full, num_steps, available=avail_full)
-        plan_l = ctx.shard_tree(plan)
-        clients = ctx.shard(clients_full)
-        partf_plan_full = plan.participating.astype(jnp.float32)
-        ov_names = sched.comp_override_names
-        ov_vals = [plan_l.comp_overrides[n] for n in ov_names]
+        with span(ROUND_SAMPLE):
+            # §11: availability-aware cohort sampling (the neutral path is
+            # the historical uniform choice, same key consumption)
+            clients_full, avail_full = sched.sample_cohort(
+                k_sample, s, state.round)
+            num_steps = self._num_local_steps(k_steps)
+            # Client-heterogeneity layer (DESIGN.md §5): per-client step
+            # counts (straggler deadline), participation mask, compressor
+            # overrides.  The full (s,) plan is computed replicated
+            # (metrics use it); the per-client compute below runs on this
+            # shard's slice (§6).
+            plan = sched.plan(clients_full, num_steps, available=avail_full)
+            plan_l = ctx.shard_tree(plan)
+            clients = ctx.shard(clients_full)
+            partf_plan_full = plan.participating.astype(jnp.float32)
+            ov_names = sched.comp_override_names
+            ov_vals = [plan_l.comp_overrides[n] for n in ov_names]
 
-        h_s = self.store.gather("h", state.h, clients)
+        ef_on = cfg.variant == "com" and cfg.error_feedback
+        with span(ROUND_STATE_GATHER):
+            h_s = self.store.gather("h", state.h, clients)
+            e_s = self.store.gather("e", state.e, clients) if ef_on else None
         # §10: with a delta-coded downlink the cohort restarts from the
         # model the clients actually HOLD (state.y — last-received), not
         # the server's exact iterate; every client-side anchor below
         # (local phase start, EF innovation, FedBuff delta) uses ref.
         ref = state.y if dl_on else state.x
-        x0 = jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(p, (s_loc,) + p.shape), ref)
 
         def local_step(carry, inp):
             x_i, loss_acc = carry
@@ -254,11 +260,14 @@ class FedComLoc(RoundEngine):
             loss_acc = loss_acc + mean_over_active(losses, active, ctx)
             return (x_i, loss_acc), None
 
-        cap = cfg.steps_cap
-        step_keys = jax.random.split(k_local, cap)
-        (x_hat, loss_sum), _ = jax.lax.scan(
-            local_step, (x0, jnp.zeros(())),
-            (jnp.arange(cap), step_keys))
+        with span(ROUND_LOCAL_PHASE):
+            x0 = jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(p, (s_loc,) + p.shape), ref)
+            cap = cfg.steps_cap
+            step_keys = jax.random.split(k_local, cap)
+            (x_hat, loss_sum), _ = jax.lax.scan(
+                local_step, (x0, jnp.zeros(())),
+                (jnp.arange(cap), step_keys))
 
         # --- communication (theta_t = 1) --------------------------------- #
         # Exact wire accounting: the dense payload is 32 bits/scalar; the
@@ -269,157 +278,171 @@ class FedComLoc(RoundEngine):
         up_bits = jnp.asarray(s * dense)
         down_bits = jnp.asarray(s * dense)
         e_new = state.e
-        innov = sent = e_s = payload = None
+        innov = sent = payload = None
         wire_on = self.wire == "packed"
-        if cfg.variant == "com":
-            up_keys = ctx.shard(jax.random.split(k_up, s))
-            if cfg.error_feedback:
-                # EF on the uplink *innovation*: transmit
-                # C(x^_i - x_prev + e_i); the server reconstructs
-                # x_prev + mean(sent).  Deltas after a local phase are small
-                # in magnitude, so TopK keeps far more of their energy than
-                # it keeps of the raw iterates; the residual stays in e_i.
-                # The uplink bits are those of the transmitted innovation.
-                e_s = self.store.gather("e", state.e, clients)
-                innov = jax.tree_util.tree_map(
-                    lambda xh, x0_, e: xh - x0_[None] + e,
-                    x_hat, ref, e_s)
-                if wire_on:
-                    # decode happens once, server-side, after the gather —
-                    # the client rows the h/e updates need are sliced back
-                    # out of the full decoded stack below
+        with span(ROUND_ENCODE):
+            if cfg.variant == "com":
+                up_keys = ctx.shard(jax.random.split(k_up, s))
+                if cfg.error_feedback:
+                    # EF on the uplink *innovation*: transmit
+                    # C(x^_i - x_prev + e_i); the server reconstructs
+                    # x_prev + mean(sent).  Deltas after a local phase are
+                    # small in magnitude, so TopK keeps far more of their
+                    # energy than it keeps of the raw iterates; the
+                    # residual stays in e_i.  The uplink bits are those of
+                    # the transmitted innovation.
+                    innov = jax.tree_util.tree_map(
+                        lambda xh, x0_, e: xh - x0_[None] + e,
+                        x_hat, ref, e_s)
+                    if wire_on:
+                        # decode happens once, server-side, after the
+                        # gather — the client rows the h/e updates need
+                        # are sliced back out of the full decoded stack
+                        payload, up_rep = ctx.encode_payload(
+                            self.comp, plan_l, innov, up_keys)
+                    else:
+                        sent, up_rep = vmap_compress(self.comp, plan_l,
+                                                     innov, up_keys)
+                        x_hat = jax.tree_util.tree_map(
+                            lambda x0_, snt: x0_[None] + snt, ref, sent)
+                elif wire_on:
+                    # §8 packed uplink: the client boundary emits the wire
+                    # payload; the round carries on with its (gathered)
+                    # decode.
                     payload, up_rep = ctx.encode_payload(
-                        self.comp, plan_l, innov, up_keys)
+                        self.comp, plan_l, x_hat, up_keys)
                 else:
-                    sent, up_rep = vmap_compress(self.comp, plan_l, innov,
-                                                 up_keys)
-                    x_hat = jax.tree_util.tree_map(
-                        lambda x0_, snt: x0_[None] + snt, ref, sent)
+                    x_hat, up_rep = vmap_compress(self.comp, plan_l, x_hat,
+                                                  up_keys)
+                # (s_loc,): the vmap axis is on the report's leaves
+                client_up = up_rep.total_bits
+                up_bits = None                 # recomputed from client_up
             elif wire_on:
-                # §8 packed uplink: the client boundary emits the wire
-                # payload; the round carries on with its (gathered) decode.
-                payload, up_rep = ctx.encode_payload(
-                    self.comp, plan_l, x_hat, up_keys)
-            else:
-                x_hat, up_rep = vmap_compress(self.comp, plan_l, x_hat,
-                                              up_keys)
-            client_up = up_rep.total_bits      # (s_loc,) — vmap axis on leaves
-            up_bits = None                     # recomputed from client_up
-        elif wire_on:
-            # uncompressed-uplink variants still move a real (dense) buffer
-            payload, _ = ctx.encode_payload(None, plan_l, x_hat)
+                # uncompressed-uplink variants still move a real (dense)
+                # buffer
+                payload, _ = ctx.encode_payload(None, plan_l, x_hat)
 
         # --- aggregation policy (DESIGN.md §7) --------------------------- #
         # The full (s,) bits each plan-participant would transmit feed the
         # finish-time clock; the policy outcome (participation, staleness,
         # weights, sim_time) is computed replicated, so it is bit-identical
         # at every §6 device count.
-        pol = aggregation.resolve_policy(
-            self.policy, sched, plan,
-            ctx.all_clients(client_up) * partf_plan_full, ctx)
-        out, part, may_exclude = pol.out, pol.part, pol.may_exclude
-        client_up = pol.client_up             # excluded clients send nothing
-        if up_bits is None or may_exclude:
-            up_bits = client_up.sum()
-        if wire_on:
-            # §8 packed uplink: the only cross-shard traffic is the masked
-            # packed-payload gather; decode happens ONCE, server-side, on
-            # the full (s,) stack — the client rows the h/e updates need
-            # are sliced back out of it (an excluded client's masked zero
-            # row never lands in state: the §5/§7 keep-old guards below
-            # are gated on the same participation mask).
-            dec_full = ctx.gather_decoded_payload(payload, out.partf)
-            if cfg.variant == "com" and cfg.error_feedback:
-                sent = ctx.shard_tree(dec_full)
-                srv_hat = jax.tree_util.tree_map(
-                    lambda x0_, sf: x0_[None] + sf, ref, dec_full)
-                x_hat = ctx.shard_tree(srv_hat)
-            else:
-                # non-com variants ship the raw iterate: decode is the
-                # identity and the local x_hat already equals its rows
-                srv_hat = dec_full
-                if cfg.variant == "com":
+        with span(ROUND_AGGREGATE):
+            pol = aggregation.resolve_policy(
+                self.policy, sched, plan,
+                ctx.all_clients(client_up) * partf_plan_full, ctx)
+            out, part, may_exclude = pol.out, pol.part, pol.may_exclude
+            client_up = pol.client_up         # excluded clients send nothing
+            if up_bits is None or may_exclude:
+                up_bits = client_up.sum()
+            if wire_on:
+                # §8 packed uplink: the only cross-shard traffic is the
+                # masked packed-payload gather; decode happens ONCE,
+                # server-side, on the full (s,) stack — the client rows the
+                # h/e updates need are sliced back out of it (an excluded
+                # client's masked zero row never lands in state: the §5/§7
+                # keep-old guards below are gated on the same participation
+                # mask).
+                dec_full = ctx.gather_decoded_payload(payload, out.partf)
+                if ef_on:
+                    sent = ctx.shard_tree(dec_full)
+                    srv_hat = jax.tree_util.tree_map(
+                        lambda x0_, sf: x0_[None] + sf, ref, dec_full)
                     x_hat = ctx.shard_tree(srv_hat)
-        if cfg.variant == "com" and cfg.error_feedback:
-            # leaky memory: undecayed EF diverges inside Scaffnew (the
-            # residual integrates against the control variates — see the
-            # EXPERIMENTS.md §Beyond decay study); 0.7 is the sweet spot.
-            e_s_new = jax.tree_util.tree_map(
-                lambda c, snt: cfg.ef_decay * (c - snt), innov, sent)
-            if may_exclude:    # an excluded client never transmitted
-                e_s_new = keep_where(part, e_s_new, e_s)
-            e_new = self.store.scatter("e", state.e, clients, e_s_new, ctx)
-        delta_combine = aggregation.uses_delta_combine(self.policy)
-        if wire_on:
-            # server aggregation from the decoded full stack, with the
-            # unsharded formula (bit-identical at any device count)
-            if delta_combine:
+                else:
+                    # non-com variants ship the raw iterate: decode is the
+                    # identity and the local x_hat already equals its rows
+                    srv_hat = dec_full
+                    if cfg.variant == "com":
+                        x_hat = ctx.shard_tree(srv_hat)
+            delta_combine = aggregation.uses_delta_combine(self.policy)
+            if wire_on:
+                # server aggregation from the decoded full stack, with the
+                # unsharded formula (bit-identical at any device count)
+                if delta_combine:
+                    delta = jax.tree_util.tree_map(
+                        lambda xh, x0_: xh - x0_[None], srv_hat, ref)
+                    x_bar = jax.tree_util.tree_map(
+                        lambda x0_, u: x0_ + u, state.x,
+                        aggregation.async_weighted_sum(out, delta, NULL_CTX))
+                elif may_exclude:
+                    x_bar = tree_where(
+                        out.n_selected > 0,
+                        masked_mean(srv_hat, out.weight, NULL_CTX,
+                                    weight_sum=out.n_selected),
+                        state.x)
+                else:
+                    x_bar = jax.tree_util.tree_map(
+                        lambda t: t.mean(axis=0), srv_hat)
+            elif delta_combine:
+                # FedBuff server application in delta form: each buffer
+                # flush applies its staleness-discounted mean of anchor
+                # deltas
                 delta = jax.tree_util.tree_map(
-                    lambda xh, x0_: xh - x0_[None], srv_hat, ref)
+                    lambda xh, x0_: xh - x0_[None], x_hat, ref)
                 x_bar = jax.tree_util.tree_map(
                     lambda x0_, u: x0_ + u, state.x,
-                    aggregation.async_weighted_sum(out, delta, NULL_CTX))
+                    aggregation.async_weighted_sum(out, delta, ctx))
             elif may_exclude:
+                # if every sampled client was excluded, the server keeps
+                # its model
                 x_bar = tree_where(out.n_selected > 0,
-                                   masked_mean(srv_hat, out.weight, NULL_CTX,
+                                   masked_mean(x_hat, pol.weight, ctx,
                                                weight_sum=out.n_selected),
                                    state.x)
             else:
-                x_bar = jax.tree_util.tree_map(
-                    lambda t: t.mean(axis=0), srv_hat)
-        elif delta_combine:
-            # FedBuff server application in delta form: each buffer flush
-            # applies its staleness-discounted mean of anchor deltas
-            delta = jax.tree_util.tree_map(
-                lambda xh, x0_: xh - x0_[None], x_hat, ref)
-            x_bar = jax.tree_util.tree_map(
-                lambda x0_, u: x0_ + u, state.x,
-                aggregation.async_weighted_sum(out, delta, ctx))
-        elif may_exclude:
-            # if every sampled client was excluded, the server keeps its
-            # model
-            x_bar = tree_where(out.n_selected > 0,
-                               masked_mean(x_hat, pol.weight, ctx,
-                                           weight_sum=out.n_selected),
-                               state.x)
-        else:
-            x_bar = ctx.mean_clients(x_hat)
-        if cfg.variant == "global":
-            x_bar, down_rep = self.comp.compress(x_bar, k_down)
-            down_bits = down_rep.total_bits * s
+                x_bar = ctx.mean_clients(x_hat)
 
         # §10 downlink seam: delta-code the new broadcast against the
         # cohort's reference, once; clients decode under the mesh (this
         # body IS the shard_map/GSPMD region) and adopt y_new.
         y_new = state.y
         dl_extras = {}
-        if dl_on:
-            y_new, down_bits, dl_extras = apply_downlink(
-                self.downlink, self.down_comp, ctx, state.y, x_bar, k_dl, s)
+        with span(ROUND_DOWNLINK):
+            if cfg.variant == "global":
+                x_bar, down_rep = self.comp.compress(x_bar, k_down)
+                down_bits = down_rep.total_bits * s
+            if dl_on:
+                y_new, down_bits, dl_extras = apply_downlink(
+                    self.downlink, self.down_comp, ctx, state.y, x_bar, k_dl,
+                    s)
         bcast = y_new if dl_on else x_bar
 
-        # line 16: h_i += (p/gamma) (x_{t+1} - x^_{i,t+1}) for i in S —
-        # x_{t+1} is the value clients ADOPT (the decoded y under a
-        # compressed downlink) and the pre-momentum mean otherwise: the
-        # extrapolation below must not leak into the control variates (it
-        # destabilises them; see tests).
-        h_s_new = jax.tree_util.tree_map(
-            lambda h, xh, xb_: h + (cfg.p / cfg.gamma) * (xb_[None] - xh),
-            h_s, x_hat, bcast)
-        if may_exclude:   # an excluded client keeps its control variate
-            h_s_new = keep_where(part, h_s_new, h_s)
-        h_new = self.store.scatter("h", state.h, clients, h_s_new, ctx)
+        with span(ROUND_STATE_UPDATE):
+            if ef_on:
+                # leaky memory: undecayed EF diverges inside Scaffnew (the
+                # residual integrates against the control variates — see
+                # the EXPERIMENTS.md §Beyond decay study); 0.7 is the sweet
+                # spot.
+                e_s_new = jax.tree_util.tree_map(
+                    lambda c, snt: cfg.ef_decay * (c - snt), innov, sent)
+                if may_exclude:    # an excluded client never transmitted
+                    e_s_new = keep_where(part, e_s_new, e_s)
+                e_new = self.store.scatter("e", state.e, clients, e_s_new,
+                                           ctx)
+            # line 16: h_i += (p/gamma) (x_{t+1} - x^_{i,t+1}) for i in S —
+            # x_{t+1} is the value clients ADOPT (the decoded y under a
+            # compressed downlink) and the pre-momentum mean otherwise: the
+            # extrapolation below must not leak into the control variates
+            # (it destabilises them; see tests).
+            h_s_new = jax.tree_util.tree_map(
+                lambda h, xh, xb_: h + (cfg.p / cfg.gamma) * (xb_[None] - xh),
+                h_s, x_hat, bcast)
+            if may_exclude:   # an excluded client keeps its control variate
+                h_s_new = keep_where(part, h_s_new, h_s)
+            h_new = self.store.scatter("h", state.h, clients, h_s_new, ctx)
 
         # beyond-paper: Polyak momentum on the broadcast point only
         mom_new = state.mom
         if cfg.server_momentum > 0:
-            delta = jax.tree_util.tree_map(
-                lambda xb_, x0_: xb_ - x0_, x_bar, state.x)
-            mom_new = jax.tree_util.tree_map(
-                lambda m, d_: cfg.server_momentum * m
-                + (1 - cfg.server_momentum) * d_, state.mom, delta)
-            x_bar = jax.tree_util.tree_map(
-                lambda x0_, m: x0_ + m, state.x, mom_new)
+            with span(ROUND_AGGREGATE):
+                delta = jax.tree_util.tree_map(
+                    lambda xb_, x0_: xb_ - x0_, x_bar, state.x)
+                mom_new = jax.tree_util.tree_map(
+                    lambda m, d_: cfg.server_momentum * m
+                    + (1 - cfg.server_momentum) * d_, state.mom, delta)
+                x_bar = jax.tree_util.tree_map(
+                    lambda x0_, m: x0_ + m, state.x, mom_new)
 
         metrics = {
             "train_loss": loss_sum / jnp.maximum(plan.steps.max(), 1),

@@ -86,6 +86,7 @@ def pack_codes(codes: jax.Array, b: int, *,
         out_shape=jax.ShapeDtypeStruct((c2d.shape[0], GROUPS * b),
                                        jnp.int32),
         interpret=interpret,
+        name="pack_codes",
     )(c2d)
     words = jax.lax.bitcast_convert_type(words2d, jnp.uint32)
     return words.reshape(-1)[: pl.cdiv(codes.size, 32) * b]
@@ -114,5 +115,6 @@ def unpack_codes(words: jax.Array, b: int, n: int, *,
         out_specs=tiling.block_spec(rows),
         out_shape=jax.ShapeDtypeStruct((slab_rows, tiling.LANES), jnp.uint32),
         interpret=interpret,
+        name="unpack_codes",
     )(w2d)
     return codes2d.reshape(-1)[:n]

@@ -68,6 +68,7 @@ def quantize_pack_with_uniforms(x: jax.Array, r: int, u: jax.Array,
         out_shape=jax.ShapeDtypeStruct((x2d.shape[0], GROUPS * b),
                                        jnp.int32),
         interpret=interpret,
+        name="qr_pack",
     )(jnp.asarray(norm, jnp.float32).reshape(1, 1), x2d, u2d)
     words = jax.lax.bitcast_convert_type(words2d, jnp.uint32)
     return words.reshape(-1)[: pl.cdiv(x.size, 32) * b]

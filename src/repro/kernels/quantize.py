@@ -72,6 +72,7 @@ def l2_norm(x: jax.Array, *, interpret: bool = False) -> jax.Array:
                                        jnp.float32),
         compiler_params=tiling.SEQUENTIAL,
         interpret=interpret,
+        name="qr_sumsq",
     )(x2d)
     return jnp.sqrt(jnp.sum(partial))
 
@@ -95,6 +96,7 @@ def quantize_qr_with_uniforms(
         out_specs=tiling.block_spec(rows),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
         interpret=interpret,
+        name="qr_quantize",
     )(norm, x2d, u2d)
     return out2d.reshape(-1)[:x.size].astype(x.dtype)
 

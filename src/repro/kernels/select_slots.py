@@ -227,20 +227,20 @@ def _one_at_a_time(fn):
     return f
 
 
-def _run_compact(kernel, x2d, scalars, blocks, n: int, cap: int, rows: int,
-                 pay_dtype, interpret: bool):
+def _run_compact(kernel, name: str, x2d, scalars, blocks, n: int, cap: int,
+                 rows: int, pay_dtype, interpret: bool):
     n_scalars = len(scalars)
 
     def run(x2d, *operands):
-        return _compact_call(kernel, x2d, operands[:n_scalars],
+        return _compact_call(kernel, name, x2d, operands[:n_scalars],
                              operands[n_scalars:], n, cap, rows, pay_dtype,
                              interpret)
 
     return _one_at_a_time(run)(x2d, *scalars, *blocks)
 
 
-def _compact_call(kernel, x2d, scalars, blocks, n: int, cap: int, rows: int,
-                  pay_dtype, interpret: bool):
+def _compact_call(kernel, name: str, x2d, scalars, blocks, n: int, cap: int,
+                  rows: int, pay_dtype, interpret: bool):
     e = rows * _LANES
     t = scalars[0]
     offs = _block_offsets(x2d, t, rows)
@@ -267,6 +267,7 @@ def _compact_call(kernel, x2d, scalars, blocks, n: int, cap: int, rows: int,
                    jax.ShapeDtypeStruct((n_out * rows, _LANES), pay_dtype)),
         compiler_params=tiling.SEQUENTIAL,
         interpret=interpret,
+        name=name,
     )(offs, *scalars, *blocks)
     filled = jnp.arange(cap, dtype=jnp.int32) < jnp.minimum(offs[-1], cap)
     idx = jnp.where(filled, idx2d.reshape(-1)[:cap], n)
@@ -286,9 +287,9 @@ def compact_slots(x: jax.Array, thr: jax.Array, cap: int, *,
         raise ValueError(f"expects 1-D input, got {x.shape}")
     rows = tiling.block_rows(x.size)
     x2d = tiling.to_slab(x, rows)
-    return _run_compact(_compact_kernel, x2d, (tiling.threshold_i32(thr),),
-                        (x2d,), x.size, int(cap), rows, jnp.float32,
-                        interpret)
+    return _run_compact(_compact_kernel, "select_slots", x2d,
+                        (tiling.threshold_i32(thr),), (x2d,), x.size,
+                        int(cap), rows, jnp.float32, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("r", "cap", "interpret"))
@@ -310,6 +311,6 @@ def compact_code_slots(x: jax.Array, u: jax.Array, norm: jax.Array,
                jnp.asarray(norm, jnp.float32).reshape(1, 1))
     idx, codes = _run_compact(
         functools.partial(_compact_code_kernel, levels=2 ** int(r)),
-        x2d, scalars, (x2d, u2d), x.size, int(cap), rows, jnp.int32,
-        interpret)
+        "select_code_slots", x2d, scalars, (x2d, u2d), x.size, int(cap),
+        rows, jnp.int32, interpret)
     return idx, codes.astype(jnp.uint32)

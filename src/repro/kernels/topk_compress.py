@@ -80,6 +80,7 @@ def _counts(x2d: jax.Array, cands: jax.Array, rows: int,
             (NCAND, tiling.SUBLANES, tiling.LANES), jnp.int32),
         compiler_params=tiling.SEQUENTIAL,
         interpret=interpret,
+        name="topk_count",
     )(cands, x2d)
     return out.sum(axis=(1, 2))
 
@@ -145,5 +146,6 @@ def topk_mask(x: jax.Array, k: int, *, interpret: bool = False) -> jax.Array:
         out_specs=tiling.block_spec(rows),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
         interpret=interpret,
+        name="topk_mask",
     )(t, x2d)
     return out2d.reshape(-1)[:x.size].astype(x.dtype)

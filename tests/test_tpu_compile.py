@@ -16,6 +16,7 @@ this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +69,21 @@ KERNELS = {
 }
 
 
+#: kernel -> the names its ``pallas_call``s give their instructions, so a
+#: device trace shows each apart
+KERNEL_NAMES = {
+    "threshold_bits": ("topk_count",),
+    "topk_mask": ("topk_count", "topk_mask"),
+    "compact_slots": ("select_slots",),
+    "compact_code_slots": ("select_code_slots",),
+    "l2_norm": ("qr_sumsq",),
+    "quantize_qr_with_uniforms": ("qr_sumsq", "qr_quantize"),
+    "pack_codes": ("pack_codes",),
+    "unpack_codes": ("unpack_codes",),
+    "quantize_pack_with_uniforms": ("qr_pack",),
+}
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -94,12 +110,20 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _named_kernels(compiled) -> set:
+    """The names of the compiled program's Pallas kernel instructions."""
+    return set(re.findall(r"%([a-z_]+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+                          compiled.as_text()))
+
+
 @pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, kernel, size):
     n = SIZES[size]
     fn, avals = KERNELS[kernel](n)
-    assert _custom_calls(_compile(fn, avals, one_chip)) >= 1
+    compiled = _compile(fn, avals, one_chip)
+    assert _custom_calls(compiled) >= 1
+    assert _named_kernels(compiled) == set(KERNEL_NAMES[kernel])
 
 
 def test_vmapped_clients_compile_for_v5e(one_chip):
@@ -137,3 +161,4 @@ def test_wire_encode_groups_leaves_by_shape(one_chip):
         ops.set_backend(prev)
     # 2 shapes x (8 threshold passes + 1 compaction), whatever the depth
     assert _custom_calls(compiled) == 2 * (len(topk_compress.DIGITS) + 1)
+    assert _named_kernels(compiled) == {"topk_count", "select_slots"}
