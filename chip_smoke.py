@@ -111,6 +111,15 @@ def _kernel_checks(x, u, k: int, r: int, b: int, codes):
     ridx, rvals, _ = ref.topk_slots(x, k, k)
     out["compact_slots"] = (jnp.sum(idx.astype(jnp.uint32) != ridx)
                             + jnp.sum(vals != rvals))
+    # the decode's placement of those slots, at f32 (topk_qr) and bf16
+    # (topk) values, and of a masked client's all-zero payload
+    out["expand_slots"] = sum(
+        jnp.sum(select_slots.expand_slots(ridx, v, n)
+                != ref.expand_slots(ridx, v, n))
+        for v in (rvals, rvals.astype(jnp.bfloat16)))
+    zeros = jnp.zeros_like(ridx)
+    out["expand_slots"] += jnp.sum(
+        select_slots.expand_slots(zeros, jnp.zeros_like(rvals), n) != 0)
     norm = quantize.l2_norm(x)
     out["l2_norm_rel"] = jnp.abs(norm - jnp.sqrt(jnp.sum(x * x))) / norm
     q = quantize.quantize_qr_with_uniforms(x, r, u)
@@ -149,6 +158,7 @@ KERNEL_LINES = (
     ("threshold", "threshold_bits: 1 where the bit pattern differs"),
     ("topk_mask", "topk_mask: entries differing"),
     ("compact_slots", "compact_slots: slots differing"),
+    ("expand_slots", "expand_slots: entries differing"),
     ("compact_code_slots_idx", "compact_code_slots: slot indices differing"),
     ("compact_code_slots", "compact_code_slots: Q_r code flips"),
     ("l2_norm_rel", "l2_norm: relative difference"),
@@ -180,7 +190,7 @@ def kernel_phase(seed: int, params) -> None:
             x, u, k, r, b, codes)
         res = {key: float(v) for key, v in res.items()}
         flips = max(1.0, QR_FLIP_RATE * n)
-        exact = ("threshold", "topk_mask", "compact_slots",
+        exact = ("threshold", "topk_mask", "compact_slots", "expand_slots",
                  "compact_code_slots_idx", "pack_codes", "unpack_codes")
         for name in exact:
             check(res[name] == 0, f"n={n}: {name} differs from the "

@@ -14,9 +14,11 @@ Codecs (one per supported operator; ``check_supported`` names the mapping):
   leaf dtype's width.
 * ``topk``    — ``TopK(impl="select")``: per unit, a static-capacity
   ``cap = k(density)`` array of ``uint32`` indices plus ``cap`` values at
-  the leaf dtype.  Empty slots (input support smaller than ``cap``, e.g.
-  error-feedback innovations) carry the sentinel index ``n`` and are
-  dropped by the decode scatter.  The static-capacity rule is what keeps
+  the leaf dtype.  Kept entries come in index order; empty slots (input
+  support smaller than ``cap``, e.g. error-feedback innovations) follow
+  with the sentinel index ``n`` and are dropped by the decode, which
+  expands the slots in place under that order (the slot-order contract of
+  :mod:`repro.kernels.select_slots`).  The static-capacity rule is what keeps
   payload shapes jit-stable inside the fused ``lax.scan``; magnitude ties
   beyond ``cap`` (measure-zero for continuous data) keep the lowest-index
   ``cap`` and drop the rest.
@@ -242,36 +244,16 @@ def _units_to_tree(units, spec: WireSpec) -> PyTree:
 # sparse (index, value) slots — static capacity, sentinel-padded
 # --------------------------------------------------------------------------- #
 #
-# Slot *extraction* lives in the fused kernels now
-# (``kops.topk_slots`` / ``kops.topk_qr_slots``: threshold select +
-# streaming compaction, no sort and no n-sized cumsum on the Pallas
-# backends); this module keeps only the decode-side scatter.
-
-def _scatter_units(entries, unit_sizes, dtype):
-    """Decode-side placement: one masked scatter for the whole payload.
-
-    ``entries`` is one ``(idx, vals)`` pair per sparse unit (sentinel-``n``
-    indices mark empty slots).  Unit indices are offset into a single
-    concatenated index space — sentinels map to ``total`` so one
-    ``mode="drop"`` scatter places every unit's survivors at once (one
-    XLA scatter instead of one per leaf), then the flat result is split
-    back into units."""
-    total = sum(unit_sizes)
-    offs, off = [], 0
-    for n in unit_sizes:
-        offs.append(off)
-        off += n
-    idx_all = jnp.concatenate([
-        jnp.where(idx < n, idx.astype(jnp.int32) + off, total)
-        for (idx, _), n, off in zip(entries, unit_sizes, offs)])
-    val_all = jnp.concatenate([v.astype(dtype) for _, v in entries])
-    flat = jnp.zeros((total,), dtype).at[idx_all].set(val_all, mode="drop")
-    return [flat[off:off + n] for off, n in zip(offs, unit_sizes)]
+# Slot extraction and placement both live in the kernels
+# (``kops.topk_slots`` / ``kops.topk_qr_slots`` encode, ``kops.expand_slots``
+# decode), under the slot-order contract of :mod:`repro.kernels.select_slots`:
+# kept entries in index order, then sentinels.
 
 
 def _by_shape(fn, units, statics):
     """``[fn(u, s) for u, s in zip(units, statics)]`` with one vmapped call
-    per distinct ``(shape, dtype, s)``.
+    per distinct ``(shapes, dtypes, s)`` (a unit is an array or a tuple of
+    arrays).
 
     A model's repeated layers then trace one kernel group per leaf shape
     (6 for a 24-layer qwen2), not one per leaf (290) — the chip's compile
@@ -280,17 +262,26 @@ def _by_shape(fn, units, statics):
     reduction may round differently from the unbatched one."""
     groups: dict = {}
     for i, (u, s) in enumerate(zip(units, statics)):
-        groups.setdefault((u.shape, jnp.dtype(u.dtype).name, s), []).append(i)
+        kind = tuple((a.shape, jnp.dtype(a.dtype).name)
+                     for a in jax.tree_util.tree_leaves(u))
+        groups.setdefault((kind, s), []).append(i)
     out = [None] * len(units)
-    for (_, _, s), members in groups.items():
+    for (_, s), members in groups.items():
         if len(members) == 1:
             out[members[0]] = fn(units[members[0]], s)
             continue
-        res = jax.vmap(lambda u: fn(u, s))(
-            jnp.stack([units[i] for i in members]))
+        res = jax.vmap(lambda u: fn(u, s))(jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *[units[i] for i in members]))
         for j, i in enumerate(members):
             out[i] = jax.tree_util.tree_map(lambda r: r[j], res)
     return out
+
+
+def _expand_units(entries, unit_sizes):
+    """Each unit's ``(idx, vals)`` slots placed into its dense vector, one
+    ``kops.expand_slots`` call per shape group (placement is exact)."""
+    return _by_shape(lambda e, n: kops.expand_slots(e[0], e[1], n),
+                     entries, unit_sizes)
 
 
 def _sparse_report_from_support(leaves, supports, scope: str) -> BitsReport:
@@ -436,9 +427,6 @@ def decode(payload: Payload) -> PyTree:
     unit_sizes = [sum(sizes)] if spec.scope == "global" else sizes
 
     if spec.codec in ("topk", "topk_qr"):
-        # One masked scatter for the whole payload: unit slots concatenate
-        # into a single offset index space (sentinels drop), so the decode
-        # issues one XLA scatter instead of one ``.at[].set`` per unit.
         entries = []
         for i, bufs in enumerate(payload.data):
             if spec.codec == "topk":
@@ -448,9 +436,7 @@ def decode(payload: Payload) -> PyTree:
                 codes = kops.unpack_codes(words, 1 + spec.r, spec.caps[i])
                 vals = _qr_values(codes, norm, spec.r)
             entries.append((idx, vals))
-        vtype = jnp.result_type(*[v.dtype for _, v in entries])
-        units = _scatter_units(entries, unit_sizes, vtype)
-        return _units_to_tree(units, spec)
+        return _units_to_tree(_expand_units(entries, unit_sizes), spec)
 
     units = []
     for bufs, n in zip(payload.data, unit_sizes):
@@ -719,16 +705,14 @@ def decode_shard_local(data, spec: WireSpec) -> PyTree:
     """Decode one client's shard-local buffers back to the local tree.
 
     The inverse of :func:`encode_shard_local` for the same shard: sparse
-    indices are local, so the scatter lands in this shard's flat slice;
+    indices are local, so the slots expand into this shard's flat slice;
     leaves come back at their LOCAL shapes (global shape with the model
     dimension divided by ``model_shards``) and the caller's ``out_specs``
     place them into the global tree.
     """
     sizes = _local_sizes(spec)
     if spec.codec == "topk":
-        entries = list(data)
-        vtype = jnp.result_type(*[v.dtype for _, v in entries])
-        units = _scatter_units(entries, sizes, vtype)
+        units = _expand_units(list(data), sizes)
     elif spec.codec == "qr":
         units = []
         for (words, norm), n in zip(data, sizes):

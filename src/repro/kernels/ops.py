@@ -189,6 +189,18 @@ def topk_qr_slots(x: jax.Array, k, cap: int, r: int, key: jax.Array):
     return idx.astype(jnp.uint32), words, norm, support
 
 
+def expand_slots(idx: jax.Array, vals: jax.Array, n: int) -> jax.Array:
+    """The decode's placement of sorted slots into a dense ``n``-vector
+    (the inverse of the compaction; slot order: DESIGN.md §8).  Pallas
+    backends run the streaming slot-expand kernel, ``ref`` one masked
+    scatter; both place the same bits."""
+    mode = _resolve()
+    if mode == "ref":
+        return _ref.expand_slots(idx, vals, int(n))
+    return _sel.expand_slots(idx, vals, int(n),
+                             interpret=(mode == "interpret"))
+
+
 def pack_codes(codes: jax.Array, b: int) -> jax.Array:
     """Bit-plane pack b-bit codes into uint32 words (wire formats, §8)."""
     mode = _resolve()

@@ -208,6 +208,13 @@ def topk_slots(x: jax.Array, k, cap: int):
     return idx.astype(jnp.uint32), vals, support
 
 
+def expand_slots(idx: jax.Array, vals: jax.Array, n: int) -> jax.Array:
+    """The dense ``n``-vector of slots ``(idx, vals)``: one masked scatter,
+    sentinel indices (``>= n``) dropped.  The oracle of
+    :func:`repro.kernels.select_slots.expand_slots`."""
+    return jnp.zeros((n,), vals.dtype).at[idx].set(vals, mode="drop")
+
+
 def topk_slots_sharded(x: jax.Array, k_global, cap: int, axis: str,
                        n_total: int, digit_bits: int = 1):
     """Shard-local slots of the exact *global* TopK (DESIGN.md §9).

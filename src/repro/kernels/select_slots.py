@@ -31,6 +31,24 @@ values themselves (the ``topk`` codec), ``compact_code_slots`` computes the
 Q_r code (sign + stochastic level, saturated) in the block body and
 compacts the *codes* (the ``topk_qr`` codec), so the dense code array
 never exists.
+
+``expand_slots`` is the decode: the same permutation run backwards, one
+grid step per dense output block.  A small XLA ``searchsorted`` gives
+output block ``b`` its slot range ``[o_b, o_{b+1})`` (prefetched into
+SMEM); the step loads the one or two slot blocks holding that range,
+rotates slot ``o_b`` to position 0, moves slot ``i`` up by
+``(idx_i - b E) - i`` with the compaction's shift network in reverse (one
+bit per step, largest first, which never collides) and zeroes every
+position no slot reached.
+
+**Slot-order contract.**  Every producer of slots — the compaction here,
+:func:`repro.kernels.ref.support_slots` and the sharded
+:func:`repro.kernels.ref.topk_slots_sharded` — emits the kept entries in
+strictly increasing index order, then sentinel slots (index ``n``, value
+0); :mod:`repro.compress.wire`'s decode relies on it through
+``expand_slots``.  The one other payload the decode meets is a masked
+non-participant's (``clients.mask_payload``): every slot index 0 and
+value 0, which decodes to zeros.
 """
 
 from __future__ import annotations
@@ -314,3 +332,101 @@ def compact_code_slots(x: jax.Array, u: jax.Array, norm: jax.Array,
         "select_code_slots", x2d, scalars, (x2d, u2d), x.size, int(cap),
         rows, jnp.int32, interpret)
     return idx, codes.astype(jnp.uint32)
+
+
+def _shift_up_by(x, s, lane):
+    """:func:`_shift_up` by a traced ``0 <= s < E``: three dynamic rolls."""
+    rows = x.shape[0]
+    z = pltpu.roll(x, (rows - s // _LANES) % rows, 0)
+    z = pltpu.roll(z, (_LANES - s % _LANES) % _LANES, 1)
+    return jnp.where(lane < _LANES - s % _LANES, z, pltpu.roll(z, rows - 1, 0))
+
+
+def _expand_kernel(offs_ref, idx_a, idx_b, val_a, val_b, out_ref):
+    """Place the slots ``[o_b, o_{b+1})`` into dense output block ``b``."""
+    b = pl.program_id(0)
+    rows = out_ref.shape[0]
+    e = rows * _LANES
+    o = offs_ref[b]
+    # a masked payload's slots all sit at index 0, so block 0 claims every
+    # one; beyond the first E they are zeros no placement needs
+    count = jnp.minimum(offs_ref[b + 1] - o, e)
+
+    @pl.when(count == 0)
+    def _empty():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(count > 0)
+    def _place():
+        _, lane, flat = _iotas(out_ref.shape)
+        # the window of E slots from o: the tail of input block o // E,
+        # then the head of the next one
+        s = o % e
+        first = flat < e - s
+        idx = jnp.where(first, _shift_up_by(idx_a[...], s, lane),
+                        _shift_up_by(idx_b[...], s, lane))
+        val = jnp.where(first, _shift_up_by(tiling.load_f32(val_a), s, lane),
+                        _shift_up_by(tiling.load_f32(val_b), s, lane))
+        # d: how far slot i moves up to its entry; -1 = no slot
+        d = idx - b * e - flat
+        d = jnp.where((flat < count) & (d >= 0) & (d < e), d, -1)
+        steps = _steps(rows)
+        for j in reversed(range(len(steps))):
+            sd = _shift_down(d, steps[j], lane)
+            sv = _shift_down(val, steps[j], lane)
+            moves = (d >= 0) & (((d >> j) & 1) == 1)
+            lands = (sd >= 0) & (((sd >> j) & 1) == 1)
+            d = jnp.where(lands, sd, jnp.where(moves, -1, d))
+            val = jnp.where(lands, sv, val)
+        out_ref[...] = jnp.where(d >= 0, val, 0.0).astype(out_ref.dtype)
+
+
+def _expand_call(idx, vals, n: int, rows: int, interpret: bool):
+    e = rows * _LANES
+    out_rows = max(pl.cdiv(n, _LANES), rows)
+    n_blocks = pl.cdiv(out_rows, rows)
+    idx = idx.astype(jnp.int32)
+    ends = jnp.minimum(jnp.arange(n_blocks + 1, dtype=jnp.int32) * e, n)
+    offs = jnp.searchsorted(idx, ends, side="left").astype(jnp.int32)
+    pad = max(1, pl.cdiv(idx.size, e)) * e - idx.size
+    idx2d = jnp.pad(idx, (0, pad), constant_values=n).reshape(-1, _LANES)
+    val2d = jnp.pad(vals, (0, pad)).reshape(-1, _LANES)
+    n_in = idx2d.shape[0] // rows
+
+    def window(k):
+        return pl.BlockSpec((rows, _LANES), lambda b, offs_ref: (
+            jnp.minimum(offs_ref[b] // e + k, n_in - 1), 0))
+
+    out = pl.pallas_call(
+        _expand_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_blocks,),
+            in_specs=[window(0), window(1), window(0), window(1)],
+            out_specs=pl.BlockSpec((rows, _LANES),
+                                   lambda b, offs_ref: (b, 0))),
+        out_shape=jax.ShapeDtypeStruct((out_rows, _LANES), vals.dtype),
+        compiler_params=tiling.SEQUENTIAL,
+        interpret=interpret,
+        name="expand_slots",
+    )(offs, idx2d, idx2d, val2d, val2d)
+    return out.reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def expand_slots(idx: jax.Array, vals: jax.Array, n: int, *,
+                 interpret: bool = False) -> jax.Array:
+    """The dense ``n``-vector of slots ``(idx, vals)``: ``vals[i]`` at
+    ``idx[i]``, zeros elsewhere, sentinel slots dropped.
+
+    The inverse of :func:`compact_slots`; the slots must keep the
+    slot-order contract of this module's docstring.  ``vals`` is bf16 or
+    f32 and sets the output dtype; placement moves bits, so the result is
+    exactly :func:`repro.kernels.ref.expand_slots`'s.
+    """
+    if idx.ndim != 1 or idx.shape != vals.shape:
+        raise ValueError(f"expects 1-D slots of one shape, got {idx.shape} "
+                         f"and {vals.shape}")
+    rows = tiling.block_rows(n)
+    return _one_at_a_time(
+        lambda i, v: _expand_call(i, v, int(n), rows, interpret))(idx, vals)

@@ -1,4 +1,4 @@
-"""Fused select+pack encode kernels (DESIGN.md §8 "fused encode kernels").
+"""Fused select+pack encode kernels and the slot-expand decode (DESIGN.md §8).
 
 Four contracts:
 
@@ -7,15 +7,17 @@ Four contracts:
    ``lax.top_k`` threshold (the pre-fusion implementation), and the Pallas
    radix walk (``topk_compress.threshold_bits``) returns the same bit
    pattern in interpret mode.
-2. **Kernel/oracle parity** — ``select_slots`` and ``qr_pack`` kernels in
-   interpret mode are bitwise equal to their ``ref.py`` oracles at the
-   edges the codec meets: k=0, k=n, cap±1 tie overflow, r=1, r=MAX_R,
-   bf16 leaves, odd/non-block-multiple sizes.
+2. **Kernel/oracle parity** — ``select_slots`` (compaction and expand)
+   and ``qr_pack`` kernels in interpret mode are bitwise equal to their
+   ``ref.py`` oracles at the edges the codec meets: k=0, k=n, cap±1 tie
+   overflow, r=1, r=MAX_R, bf16 leaves, odd/non-block-multiple sizes,
+   trailing sentinels and masked payloads.
 3. **Dispatch parity** — ``ops.topk_slots`` / ``quantize_pack`` /
-   ``topk_qr_slots`` agree between the ``ref`` and ``interpret`` backends,
-   including under ``vmap`` (the client axis).
-4. **Wire integration** — ``wire.encode`` payloads are identical across
-   backends, and ``decode(encode(x))`` still equals the transform output.
+   ``topk_qr_slots`` / ``expand_slots`` agree between the ``ref`` and
+   ``interpret`` backends, including under ``vmap`` (the client axis).
+4. **Wire integration** — ``wire.encode`` payloads and ``wire.decode``
+   trees are identical across backends, and ``decode(encode(x))`` still
+   equals the transform output.
 
 Everything runs on CPU (interpret mode executes the kernel bodies with
 jnp semantics); the CI matrix runs this file on both the single-device
@@ -28,6 +30,7 @@ import numpy as np
 import pytest
 
 from repro.compress import Compose, QuantQr, TopK, wire
+from repro.core import clients
 from repro.kernels import ops
 from repro.kernels import qr_pack
 from repro.kernels import ref
@@ -194,6 +197,77 @@ class TestCompactSlots:
         assert (vals_r == vals_p).all()
 
 
+def _slots(n, kept, cap, seed=0, dtype=jnp.float32, masked=False):
+    """``cap`` slots of ``kept`` random sorted indices below ``n`` (the
+    lowest ``cap`` of them where ``kept > cap``), then sentinels ``n``;
+    some values are negative zeros, which placement must keep.  ``masked``
+    gives the all-zero payload of ``clients.mask_payload``."""
+    if masked:
+        return jnp.zeros(cap, jnp.uint32), jnp.zeros(cap, dtype)
+    rng = np.random.default_rng(seed)
+    picked = np.sort(rng.choice(n, size=kept, replace=False))[:cap]
+    idx = np.full(cap, n, np.uint32)
+    idx[:picked.size] = picked
+    vals = np.zeros(cap, np.float32)
+    vals[:picked.size] = rng.normal(size=picked.size)
+    vals[:picked.size:7] = -0.0
+    return jnp.asarray(idx), jnp.asarray(vals).astype(dtype)
+
+
+def _bits(x):
+    """Bit patterns, so that -0.0 and 0.0 count as different."""
+    width = {2: jnp.uint16, 4: jnp.uint32}[jnp.dtype(x.dtype).itemsize]
+    return jax.lax.bitcast_convert_type(x, width)
+
+
+E_MAX = 512 * 128                        # entries of the largest grid block
+
+#: name -> (n, kept, cap, masked)
+EXPAND_CASES = {
+    "below_one_block": (100, 10, 10, False),
+    "ragged": (5000, 500, 500, False),
+    "several_blocks": (5 * E_MAX // 2 + 77, 8200, 8200, False),
+    "crosses_input_blocks": (3 * E_MAX + 300, 2 * E_MAX + 5000,
+                             2 * E_MAX + 5000, False),
+    "full_support": (300, 300, 300, False),
+    "under_filled": (1030, 50, 120, False),
+    "all_sentinels": (1030, 0, 64, False),
+    "masked": (1030, 0, 100, True),
+    "masked_beyond_one_block": (3 * E_MAX, 0, E_MAX + 4000, True),
+}
+
+
+class TestExpandSlots:
+    """``expand_slots`` in interpret mode places exactly the bits of its
+    oracle's masked scatter."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", sorted(EXPAND_CASES))
+    def test_parity(self, case, dtype):
+        n, kept, cap, masked = EXPAND_CASES[case]
+        idx, vals = _slots(n, kept, cap, seed=len(case), dtype=dtype,
+                           masked=masked)
+        out = sel.expand_slots(idx, vals, n, interpret=True)
+        expect = ref.expand_slots(idx, vals, n)
+        assert out.shape == (n,) and out.dtype == dtype
+        assert (_bits(out) == _bits(expect)).all()
+        if masked:
+            assert (_bits(out) == 0).all()
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_vmap_two_clients(self, dtype):
+        # one client's payload as sent, the other's masked
+        n = E_MAX + 999
+        idx, vals = _slots(n, 4000, 4000, seed=3, dtype=dtype)
+        idx = jnp.stack([idx, jnp.zeros_like(idx)])
+        vals = jnp.stack([vals, jnp.zeros_like(vals)])
+        out = jax.vmap(lambda i, v: sel.expand_slots(
+            i, v, n, interpret=True))(idx, vals)
+        expect = jax.vmap(lambda i, v: ref.expand_slots(i, v, n))(idx, vals)
+        assert (_bits(out) == _bits(expect)).all()
+        assert (_bits(out[1]) == 0).all()
+
+
 class TestQrPack:
     @pytest.mark.parametrize("n", [33, 1024, 1030, 5000])
     @pytest.mark.parametrize("r", [1, 4, wire.MAX_R])
@@ -300,6 +374,15 @@ class TestOpsParity:
         for a, b in zip(out["interpret"], out["ref"]):
             assert (a == b).all()
 
+    def test_expand_slots(self):
+        idx, vals = _slots(1030, 100, 120, seed=24, dtype=jnp.bfloat16)
+        ops.set_backend("interpret")
+        d1 = ops.expand_slots(idx, vals, 1030)
+        ops.set_backend("ref")
+        d2 = ops.expand_slots(idx, vals, 1030)
+        assert d1.dtype == d2.dtype == jnp.bfloat16
+        assert (_bits(d1) == _bits(d2)).all()
+
     def test_traced_k_routes_to_ref(self):
         # per-client densities: traced k must not hit the static kernels
         ops.set_backend("interpret")
@@ -333,6 +416,16 @@ def _tree():
     }
 
 
+DECODE_COMPS = [
+    TopK(density=0.1),
+    TopK(density=0.1, scope="global"),
+    Compose(TopK(0.1), QuantQr(4)),
+    Compose(TopK(0.1, scope="global"), QuantQr(4, scope="global")),
+]
+DECODE_IDS = ["topk-tensor", "topk-global", "topk_qr-tensor",
+              "topk_qr-global"]
+
+
 class TestWireBackendParity:
     @pytest.mark.parametrize("comp", WIRE_COMPS,
                              ids=lambda c: type(c).__name__ + getattr(
@@ -354,6 +447,26 @@ class TestWireBackendParity:
                 else:
                     assert (buf_r == buf_i).all()
         assert float(rep_ref.total_bits) == float(rep_int.total_bits)
+
+    @pytest.mark.parametrize("comp", DECODE_COMPS, ids=DECODE_IDS)
+    def test_decode_through_kernel(self, comp):
+        """The Pallas decode (interpret) of two clients' payloads, one of
+        them masked, equals the ref decode bit for bit."""
+        tree = dict(_tree(), h=jax.random.normal(
+            jax.random.PRNGKey(3), (67,)).astype(jnp.bfloat16))
+        ops.set_backend("ref")
+        payload, _ = wire.encode(comp, tree, jax.random.PRNGKey(9))
+        both = jax.tree_util.tree_map(lambda a: jnp.stack([a, a]), payload)
+        both = clients.mask_payload(both, jnp.asarray([1.0, 0.0]))
+        out = {}
+        for backend in ("interpret", "ref"):
+            ops.set_backend(backend)
+            out[backend] = jax.jit(jax.vmap(wire.decode))(both)
+        for a, b in zip(jax.tree_util.tree_leaves(out["interpret"]),
+                        jax.tree_util.tree_leaves(out["ref"])):
+            assert a.dtype == b.dtype
+            assert (_bits(a) == _bits(b)).all()
+            assert (_bits(a[1]) == 0).all()
 
     def test_decode_roundtrip_interpret(self):
         tree, key = _tree(), jax.random.PRNGKey(8)

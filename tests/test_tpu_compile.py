@@ -66,6 +66,9 @@ KERNELS = {
     "quantize_pack_with_uniforms": lambda n: (
         lambda x, u, nrm: qr_pack.quantize_pack_with_uniforms(x, R, u, nrm),
         [((n,), jnp.bfloat16), ((n,), jnp.float32), ((), jnp.float32)]),
+    "expand_slots": lambda n: (
+        lambda i, v: select_slots.expand_slots(i, v, n),
+        [((_k(n),), jnp.uint32), ((_k(n),), jnp.bfloat16)]),
 }
 
 
@@ -81,6 +84,7 @@ KERNEL_NAMES = {
     "pack_codes": ("pack_codes",),
     "unpack_codes": ("unpack_codes",),
     "quantize_pack_with_uniforms": ("qr_pack",),
+    "expand_slots": ("expand_slots",),
 }
 
 
@@ -138,6 +142,43 @@ def test_vmapped_clients_compile_for_v5e(one_chip):
 
     compiled = _compile(jax.vmap(enc), [((2, n), jnp.bfloat16)], one_chip)
     assert _custom_calls(compiled) >= 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_vmapped_expand_compiles_for_v5e(one_chip, size, dtype):
+    """The decode's slot expand for 2 clients under ``vmap``, at each leaf
+    size, with bf16 (``topk``) and f32 (``topk_qr``) values."""
+    n = SIZES[size]
+    k = _k(n)
+    compiled = _compile(
+        jax.vmap(lambda i, v: select_slots.expand_slots(i, v, n)),
+        [((2, k), jnp.uint32), ((2, k), dtype)], one_chip)
+    assert _named_kernels(compiled) == {"expand_slots"}
+
+
+def test_wire_decode_groups_leaves_by_shape(one_chip):
+    """The packed ``topk`` decode of two clients' two-layer trees on the
+    Pallas path expands one kernel group per leaf shape."""
+    tree = {f"layer_{i}": {"q": jnp.zeros((896, 896), jnp.bfloat16),
+                           "b": jnp.zeros((896,), jnp.bfloat16)}
+            for i in range(2)}
+    payload = jax.eval_shape(lambda t: wire.encode(TopK(0.05), t)[0], tree)
+    bufs, treedef = jax.tree_util.tree_flatten(payload)
+    avals = [((2,) + b.shape, b.dtype) for b in bufs]
+
+    def decode(*bufs):
+        return jax.vmap(wire.decode)(jax.tree_util.tree_unflatten(
+            treedef, bufs))
+
+    prev = ops.get_backend()
+    ops.set_backend("pallas")
+    try:
+        compiled = _compile(decode, avals, one_chip)
+    finally:
+        ops.set_backend(prev)
+    assert _custom_calls(compiled) == 2
+    assert _named_kernels(compiled) == {"expand_slots"}
 
 
 def test_wire_encode_groups_leaves_by_shape(one_chip):
